@@ -612,7 +612,8 @@ class Solution:
                 model_axis=self.hw.chips, kind=kind,
             )
             return Deployment(cfgs=cfgs, plans={cfgs[0].name: plan},
-                              multi=None, mesh_axes=mesh_axes)
+                              multi=None, mesh_axes=mesh_axes,
+                              chips=self.hw.chips)
         wl = self.problem.workload
         mm = self.multi
         # Only reuse the solved co-schedule when it was built from these
@@ -634,7 +635,7 @@ class Solution:
             step=step, hw=self.hw, switch_cost=switch_cost, mm=mm,
         )
         return Deployment(cfgs=cfgs, plans=plans, multi=mm,
-                          mesh_axes=mesh_axes)
+                          mesh_axes=mesh_axes, chips=self.hw.chips)
 
     # -------------------------------------------------------------- serving
     def as_multimodel(self) -> MultiModelSchedule:
@@ -945,11 +946,7 @@ class Solution:
         if measure:
             dep = self.deploy()
             if mesh is None:
-                import jax
-
-                from .launch.mesh import make_mesh
-
-                mesh = make_mesh((1, len(jax.devices())), ("data", "model"))
+                mesh = dep.make_mesh()
             service_override = measure_service_models(dep, mesh,
                                                       seq_len=seq_len)
 
@@ -1208,21 +1205,45 @@ class Solution:
 class Deployment:
     """Runtime-facing view of a solution: per-model ShardPlans.
 
-    ``build_steps`` jits the serving steps on a mesh
-    (:func:`repro.runtime.serve.build_multimodel_steps`).
+    The plans were derived for a ``model`` axis of ``chips`` chips, the
+    solved package's size.  ``make_mesh`` builds that mesh from the visible
+    devices; ``build_steps`` jits the serving steps on a mesh
+    (:func:`repro.runtime.serve.build_multimodel_steps`) and refuses one
+    whose ``model`` axis is not the package the plans were solved for.
     """
     cfgs: tuple
     plans: dict
     multi: MultiModelSchedule | None
     mesh_axes: tuple[str, ...]
+    chips: int
 
     def plan(self, name: str):
         return self.plans[name]
+
+    def make_mesh(self):
+        """``(1, .., chips)`` mesh over the first ``chips`` visible devices."""
+        import jax
+
+        from .launch.mesh import make_mesh
+
+        devices = jax.devices()
+        if len(devices) < self.chips:
+            raise ValueError(
+                f"the deployment was solved for {self.chips} chips, but only "
+                f"{len(devices)} devices are visible"
+            )
+        shape = (1,) * (len(self.mesh_axes) - 1) + (self.chips,)
+        return make_mesh(shape, self.mesh_axes, devices=devices[:self.chips])
 
     def build_steps(self, mesh, batch: int | None = None,
                     max_len: int | None = None, with_decode: bool = True):
         from .runtime.serve import build_multimodel_steps
 
+        if mesh.shape["model"] != self.chips:
+            raise ValueError(
+                f"mesh model axis has {mesh.shape['model']} devices, but the "
+                f"deployment was solved for {self.chips} chips"
+            )
         return build_multimodel_steps(
             list(self.cfgs), mesh, self.plans,
             batch=batch, max_len=max_len, with_decode=with_decode,

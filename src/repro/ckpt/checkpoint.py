@@ -32,10 +32,7 @@ def _resolve_dtype(name: str):
 
 
 def _leaf_paths(tree):
-    try:
-        flat, treedef = jax.tree.flatten_with_path(tree)
-    except AttributeError:  # jax < 0.5: only the tree_util spelling exists
-        flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     keys = ["/".join(str(p) for p in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]
     return keys, leaves, treedef

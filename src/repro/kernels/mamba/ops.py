@@ -10,7 +10,7 @@ from .ref import mamba_scan_ref
 
 
 @partial(jax.jit, static_argnames=("block_d", "chunk", "impl", "interpret"))
-def mamba_scan(dt, x, A, Bc, Cc, D, block_d: int = 128, chunk: int = 64,
+def mamba_scan(dt, x, A, Bc, Cc, D, block_d: int = 128, chunk: int = 128,
                impl: str = "pallas", interpret: bool = False):
     if impl == "ref":
         return mamba_scan_ref(dt, x, A, Bc, Cc, D)

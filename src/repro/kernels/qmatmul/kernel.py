@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._compat.pallas import CompilerParams as _CompilerParams
-
 
 def _qmm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_scr, *, k_steps: int):
     kk = pl.program_id(2)
@@ -33,11 +31,9 @@ def _qmm_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_scr, *, k_steps: int):
 
     @pl.when(kk == k_steps - 1)
     def _final():
-        xs = xs_ref[...].astype(jnp.float32)       # [bm]
-        ws = ws_ref[...].astype(jnp.float32)       # [bn]
-        o_ref[...] = (
-            acc_scr[...].astype(jnp.float32) * xs[:, None] * ws[None, :]
-        ).astype(o_ref.dtype)
+        xs = xs_ref[...].astype(jnp.float32)       # [bm, 1]
+        ws = ws_ref[...].astype(jnp.float32)       # [1, bn]
+        o_ref[...] = (acc_scr[...].astype(jnp.float32) * xs * ws).astype(o_ref.dtype)
 
 
 def qmatmul_kernel(
@@ -63,14 +59,16 @@ def qmatmul_kernel(
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_m,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
+            # scales as [M, 1] / [1, N]: 2-D blocks follow the TPU tiling,
+            # where a 1-D block's layout differs from XLA's
+            pl.BlockSpec((block_m, 1), lambda i, j, kk: (i, 0)),
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, w, x_scale, w_scale)
+    )(x, w, x_scale.reshape(M, 1), w_scale.reshape(1, N))

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..._compat.pallas import CompilerParams as _CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref, s_scr, *,
                 chunk: int, n_chunks: int):
@@ -45,9 +43,15 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref, s_scr, *,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)     # log decay, <= 0
-    u = u_ref[0].astype(jnp.float32)          # [hd]
+    u = u_ref[0].astype(jnp.float32)          # [1, hd]
 
-    clw = jnp.cumsum(lw, axis=0)              # inclusive per-channel cum-decay
+    # inclusive per-channel cum-decay, as a lower-triangular matmul (the TPU
+    # lowering has no cumsum); HIGHEST keeps the log-decays in full fp32
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    tj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    clw = jax.lax.dot_general(
+        jnp.where(tj <= ti, 1.0, 0.0), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
     clw_excl = clw - lw                       # exclusive
     rt = r * jnp.exp(clw_excl)                # decayed receptance
     kt = k * jnp.exp(-clw)                    # inverse-decayed keys
@@ -55,21 +59,23 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_out_ref, s_scr, *,
     # intra-chunk attention-like term (strictly causal) + u-bonus diagonal
     a = jax.lax.dot_general(rt, kt, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # [T, T]
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    tj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     a = jnp.where(tj < ti, a, 0.0)
     intra = jax.lax.dot_general(a, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    diag = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True) * v
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True) * v
 
     cross = jax.lax.dot_general(rt, s_scr[...], (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     o_ref[0, 0] = (cross + intra + diag).astype(o_ref.dtype)
 
     # state update
-    total = clw[-1]                            # [hd]
-    kdec = k * jnp.exp(total[None, :] - clw)   # keys decayed to chunk end
-    s_new = jnp.exp(total)[:, None] * s_scr[...] + jax.lax.dot_general(
+    total = clw[chunk - 1:chunk]               # [1, hd]
+    kdec = k * jnp.exp(total - clw)            # keys decayed to chunk end
+    # the same chunk decay as a column [hd, 1], to scale the state's rows
+    total_col = jax.lax.dot_general(
+        lw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+    s_new = jnp.exp(total_col) * s_scr[...] + jax.lax.dot_general(
         kdec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     s_scr[...] = s_new
@@ -99,8 +105,10 @@ def wkv6_kernel(
     return pl.pallas_call(
         kernel,
         grid=grid,
+        # u as [H, 1, hd]: its block is whole in the last two dims, as the
+        # TPU tiling requires (a (1, hd) block of [H, hd] is not)
         in_specs=[tile, tile, tile, tile,
-                  pl.BlockSpec((1, hd), lambda b, h, c: (h, 0))],
+                  pl.BlockSpec((1, 1, hd), lambda b, h, c: (h, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, hd, hd), lambda b, h, c: (b, h, 0, 0)),
@@ -110,8 +118,8 @@ def wkv6_kernel(
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u.reshape(H, 1, hd))

@@ -1,15 +1,17 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # The two lines above MUST run before any jax-importing module: jax locks the
-# device count at first init, and the production dry-run needs 512 host
+# device count at first init, and the production dry-run needs 512 CPU
 # placeholder devices to build the 16x16 and 2x16x16 meshes.
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell.
 
-For each cell this proves, without hardware:
+The target is XLA's CPU backend on 512 placeholder devices, not the TPU
+compiler.  For each cell this shows:
   * the sharding plan is coherent (GSPMD partitions every op),
-  * the program fits (memory_analysis),
-  * and it yields the roofline inputs (cost_analysis + HLO collective bytes).
+  * the CPU backend's per-device memory_analysis (not a TPU HBM fit),
+  * and the roofline inputs (cost_analysis + HLO collective bytes).
+What the TPU compiler accepts is checked by tests/test_tpu_compile.py.
 
 Usage:
   python -m repro.launch.dryrun --arch granite-3-8b --shape train_4k

@@ -11,10 +11,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
-from repro.models import forward, init_kv_cache, init_params
 from repro.runtime.planner import plan_for_cell
-from repro.runtime.serve import build_decode_step, greedy_generate
+from repro.runtime.serve import (
+    build_decode_step,
+    greedy_generate,
+    init_sharded_cache,
+    init_sharded_params,
+)
 
 
 def main() -> None:
@@ -33,29 +38,34 @@ def main() -> None:
     max_len = args.prompt_len + args.tokens
     plan = plan_for_cell(cfg, max_len, args.batch, ("data", "model"),
                          model_axis=dims[1], kind="decode")
-    params = init_params(cfg, jax.random.PRNGKey(0))
 
     # prefill the prompt token-by-token through the decode path (exercises
     # exactly the serve_step the dry-run lowers)
-    dstep, _ = build_decode_step(cfg, mesh, plan, batch=args.batch, max_len=max_len)
-    caches = init_kv_cache(cfg, args.batch, max_len,
-                           jnp.float32 if args.smoke else jnp.bfloat16)
+    dstep, specs = build_decode_step(cfg, mesh, plan, batch=args.batch,
+                                     max_len=max_len)
+    params = init_sharded_params(cfg, mesh, specs["params"],
+                                 jax.random.PRNGKey(0))
+    caches = init_sharded_cache(cfg, mesh, specs["caches"], args.batch, max_len,
+                                jnp.float32 if args.smoke else jnp.bfloat16)
     prompt = jax.random.randint(jax.random.PRNGKey(1),
                                 (args.batch, args.prompt_len), 0, cfg.vocab)
-    tok = prompt[:, :1]
     for t in range(args.prompt_len):
         pos = jnp.full((args.batch,), t, jnp.int32)
         logits, caches = dstep(params, prompt[:, t:t + 1], pos, caches)
-    t0 = time.time()
+    first = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+    jax.block_until_ready(first)
+    t0 = time.perf_counter()
     out, _ = greedy_generate(cfg, params, dstep, caches,
-                             prompt_last_token=jnp.argmax(logits[:, -1], -1)
-                             .astype(jnp.int32)[:, None],
+                             prompt_last_token=first,
                              start_pos=args.prompt_len, steps=args.tokens)
-    dt = time.time() - t0
+    jax.block_until_ready(out)
+    dt = time.perf_counter() - t0
     print(f"generated {out.shape} in {dt:.2f}s "
-          f"({args.batch * args.tokens / dt:.1f} tok/s)")
+          f"({args.batch * args.tokens / dt:.1f} tok/s) "
+          f"on {jax.devices()[0].device_kind}")
     print("sample:", out[0, :16].tolist())
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

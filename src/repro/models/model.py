@@ -55,21 +55,25 @@ def _init_block(key, cfg: ModelConfig, kind: str, layer_idx: int, dtype) -> dict
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
+    """Seeded random weights.  Layer ``r * P + pi`` draws from
+    ``keys[r * P + pi]``; each pattern position is vmapped over its repeats,
+    so the stack is built in one piece (pattern positions share MoE
+    placement across repeats, see ``expanded_pattern``).  Run it under
+    ``jax.jit(..., out_shardings=...)`` (``runtime.serve.init_sharded_params``)
+    to create the weights directly in their shards."""
     dtype = jnp.dtype(cfg.param_dtype)
     R = cfg.pattern_repeats
     P = len(cfg.expanded_pattern)
     keys = jax.random.split(key, R * P + 2)
-    blocks = []
-    for pi, kind in enumerate(cfg.expanded_pattern):
-        stacked = [
-            _init_block(keys[r * P + pi], cfg, kind, r * P + pi, dtype)
-            for r in range(R)
-        ]
-        blocks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *stacked))
+    blocks = tuple(
+        jax.vmap(partial(_init_block, cfg=cfg, kind=kind, layer_idx=pi,
+                         dtype=dtype))(keys[pi:R * P:P])
+        for pi, kind in enumerate(cfg.expanded_pattern)
+    )
     params = {
         "embed": (jax.random.normal(keys[-2], (cfg.padded_vocab, cfg.d_model))
                   * cfg.d_model ** -0.5).astype(dtype),
-        "blocks": tuple(blocks),
+        "blocks": blocks,
         "final_ln": jnp.zeros((cfg.d_model,), jnp.float32),
     }
     if not cfg.tie_embeddings:

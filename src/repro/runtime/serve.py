@@ -22,6 +22,23 @@ def _sanitized_param_specs(cfg, plan, mesh):
     return sanitize_pspecs(param_pspecs(cfg, plan, mesh), shapes, mesh)
 
 
+def init_sharded_params(cfg: ModelConfig, mesh: Mesh, param_specs,
+                        key: jax.Array) -> dict:
+    """Seeded weights created inside one jitted program, each directly in
+    its shard of ``param_specs`` (from ``build_*_step``): no weight is
+    ever whole on one device, and no per-layer copy outlives the stack."""
+    return jax.jit(init_params, static_argnums=0,
+                   out_shardings=to_shardings(mesh, param_specs))(cfg, key)
+
+
+def init_sharded_cache(cfg: ModelConfig, mesh: Mesh, cache_specs, batch: int,
+                       max_len: int, dtype=jnp.bfloat16) -> tuple:
+    """Zeroed KV/state cache created in its shards of ``cache_specs``."""
+    return jax.jit(init_kv_cache, static_argnums=(0, 1, 2, 3),
+                   out_shardings=to_shardings(mesh, cache_specs))(
+        cfg, batch, max_len, dtype)
+
+
 def build_prefill_step(cfg: ModelConfig, mesh: Mesh, plan: ShardPlan):
     """Signature depends on the frontend:
     none        -> prefill(params, tokens)

@@ -1071,7 +1071,8 @@ def measure_service_models(
     iters: int = 3,
 ) -> dict[str, ServiceModel]:
     """Time the real jitted prefill steps from ``build_multimodel_steps``
-    on host devices and fit ``service = overhead + b * beat`` per model.
+    on ``mesh``'s devices (the chip, where one is attached) and fit
+    ``service = overhead + b * beat`` per model.
 
     The two-point fit at batch sizes ``batches`` separates the fixed
     per-batch overhead from the per-sample slope; the returned models plug
@@ -1083,7 +1084,7 @@ def measure_service_models(
     import jax
     import jax.numpy as jnp
 
-    from ..models import init_params
+    from ..runtime.serve import init_sharded_params
 
     b_lo, b_hi = batches
     if not (0 < b_lo < b_hi):
@@ -1092,7 +1093,8 @@ def measure_service_models(
     out: dict[str, ServiceModel] = {}
     for cfg in deployment.cfgs:
         prefill = fleet[cfg.name]["prefill"]
-        params = init_params(cfg, jax.random.PRNGKey(0))
+        params = init_sharded_params(cfg, mesh, fleet[cfg.name]["param_specs"],
+                                     jax.random.PRNGKey(0))
         timed = {}
         for b in (b_lo, b_hi):
             toks = jnp.ones((b, seq_len), jnp.int32)

@@ -41,7 +41,7 @@ def main():
             p, cfg, t, l, constrain=c1, constrain2=c2,
             transition_repeat=plan.transition_repeat,
         ))
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             loss = float(fn(params, toks, labels))
             hlo = fn.lower(params, toks, labels).compile().as_text()
         stats = collective_stats(hlo)

@@ -332,6 +332,101 @@ class TestDeployment:
         with pytest.raises(ValueError, match="ModelConfigs"):
             sol.deploy(global_batch=8)
 
+    def test_mesh_must_match_solved_package(self, lm_setup):
+        """Plans are derived for the solved package's chip count: a mesh
+        of any other size is refused, and so is a package larger than the
+        visible devices."""
+        from repro.core.hw import tpu_v5e
+        from repro.launch.mesh import single_device_mesh
+
+        cfgs, _ = lm_setup
+        wl = scope.WorkloadSpec.lm(cfgs[:1], seq_len=64)
+        dep = scope.solve(scope.problem(wl, tpu_v5e(8, (1, 8)),
+                                        m_samples=8)).deploy(global_batch=8)
+        assert dep.chips == 8
+        with pytest.raises(ValueError, match="solved for 8 chips"):
+            dep.build_steps(single_device_mesh())
+        with pytest.raises(ValueError, match="solved for 8 chips"):
+            dep.make_mesh()
+
+    def test_sharded_init_serves_through_deploy(self, lm_setup):
+        """solve -> deploy -> make_mesh -> build_steps on one device, with
+        weights and cache created in their shards by one jitted program each;
+        the jitted weights are the eager ``init_params`` ones (to rounding
+        of the init scale constants)."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from repro.core.hw import tpu_v5e
+        from repro.models import init_params
+        from repro.runtime.serve import init_sharded_cache, init_sharded_params
+        from repro.runtime.sharding import to_shardings
+
+        cfg = lm_setup[0][0]
+        dep = scope.solve(scope.problem(scope.WorkloadSpec.lm([cfg], seq_len=16),
+                                        tpu_v5e(1, (1, 1)),
+                                        m_samples=8)).deploy(global_batch=2)
+        mesh = dep.make_mesh()
+        assert dict(mesh.shape) == {"data": 1, "model": 1}
+        steps = dep.build_steps(mesh, batch=2, max_len=24)[cfg.name]
+        key = jax.random.PRNGKey(0)
+        params = init_sharded_params(cfg, mesh, steps["param_specs"], key)
+        want = to_shardings(mesh, steps["param_specs"])
+        for leaf, sh in zip(jax.tree.leaves(params), jax.tree.leaves(want)):
+            assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+        for a, b in zip(jax.tree.leaves(params),
+                        jax.tree.leaves(init_params(cfg, key))):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7)
+        caches = init_sharded_cache(cfg, mesh, steps["cache_specs"], 2, 24,
+                                    jnp.float32)
+        toks = jnp.ones((2, 16), jnp.int32)
+        logits = steps["prefill"](params, toks)
+        step_logits, _ = steps["decode"](params, toks[:, :1],
+                                         jnp.zeros((2,), jnp.int32), caches)
+        np.testing.assert_allclose(np.asarray(step_logits[:, 0]),
+                                   np.asarray(logits[:, 0]),
+                                   rtol=1e-4, atol=1e-4)
+
+
+class TestCompileCache:
+    """Entry points keep JAX's compile cache at one fixed place."""
+
+    def test_environment_variable_is_left_to_jax(self, monkeypatch, tmp_path):
+        import jax
+
+        from repro.launch.compile_cache import use_compile_cache
+
+        before = (jax.config.jax_compilation_cache_dir,
+                  jax.config.jax_persistent_cache_min_compile_time_secs)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert (jax.config.jax_compilation_cache_dir,
+                jax.config.jax_persistent_cache_min_compile_time_secs) == before
+
+    def test_default_is_checkout_dot_jax_cache(self, monkeypatch):
+        from pathlib import Path
+
+        import jax
+
+        import repro
+        from repro.launch.compile_cache import use_compile_cache
+
+        before = (jax.config.jax_compilation_cache_dir,
+                  jax.config.jax_persistent_cache_min_compile_time_secs)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            path = use_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == path
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before[0])
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              before[1])
+        checkout = Path(repro.__file__).resolve().parents[2]
+        assert path == str(checkout / ".jax_cache")
+
 
 # ------------------------------------------------------------------- CLI
 

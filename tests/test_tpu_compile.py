@@ -1,0 +1,145 @@
+"""Ahead-of-time compiles for a described TPU v5e chip, at real widths.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a topology that is described, not present.  These tests catch what
+interpret mode cannot -- block shapes off the (8, 128) tiling, primitives
+the Pallas TPU lowering lacks, programs that do not fit the chip's HBM.
+Nothing runs, so they say nothing about results or times.
+
+The topology is described inside a fixture, never at import: only one
+process may hold the TPU library, and pytest-xdist workers all import this
+file.
+"""
+import os
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro import scope
+from repro.configs import get_config
+from repro.core.hw import tpu_v5e
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.mamba.ops import mamba_scan
+from repro.kernels.qmatmul.ops import qmatmul
+from repro.kernels.rwkv6.ops import wkv6
+from repro.models import init_kv_cache, init_params
+from repro.runtime.sharding import to_shardings
+
+HBM_BYTES = 16 * 10**9      # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------------------------ kernels
+
+def test_flash_attention_compiles(one_chip):
+    """granite-3-8b attention: 32 heads, 8 KV heads, hd 128, S 2048."""
+    q = _spec(one_chip, (1, 32, 2048, 128), jnp.bfloat16)
+    kv = _spec(one_chip, (1, 8, 2048, 128), jnp.bfloat16)
+    _assert_kernel(flash_attention.lower(q, kv, kv).compile())
+
+
+def test_wkv6_compiles(one_chip):
+    """rwkv6-3b widths: d_model / head_dim = 40 heads of 64."""
+    cfg = get_config("rwkv6-3b")
+    H, hd = cfg.d_model // cfg.rwkv_head_dim, cfg.rwkv_head_dim
+    x = _spec(one_chip, (1, H, 512, hd))
+    _assert_kernel(wkv6.lower(x, x, x, x, _spec(one_chip, (H, hd))).compile())
+
+
+def test_mamba_scan_compiles(one_chip):
+    """jamba widths: d_inner = 2 x 4096 = 8192, d_state 16."""
+    cfg = get_config("jamba-v0.1-52b")
+    di, N = cfg.mamba_expand * cfg.d_model, cfg.mamba_d_state
+    sd = _spec(one_chip, (1, 512, di))
+    sn = _spec(one_chip, (1, 512, N))
+    compiled = mamba_scan.lower(sd, sd, _spec(one_chip, (di, N)), sn, sn,
+                                _spec(one_chip, (di,))).compile()
+    _assert_kernel(compiled)
+
+
+def test_qmatmul_compiles(one_chip):
+    """granite-3-8b FFN up-projection in int8: 512x4096 @ 4096x12800."""
+    x = _spec(one_chip, (512, 4096), jnp.int8)
+    w = _spec(one_chip, (4096, 12800), jnp.int8)
+    compiled = qmatmul.lower(x, w, _spec(one_chip, (512,)),
+                             _spec(one_chip, (12800,))).compile()
+    _assert_kernel(compiled)
+
+
+# ---------------------------------------------------------- main-path steps
+
+def _granite_steps(one_chip, batch, max_len):
+    """granite-3-8b cut to 20 of 40 layers, solved on a one-chip package
+    and built through deploy on a (1, 1) mesh of the described chip."""
+    cfg = replace(get_config("granite-3-8b"), n_layers=20)
+    sol = scope.solve(scope.problem(scope.WorkloadSpec.lm([cfg], 512),
+                                    tpu_v5e(1, (1, 1))))
+    dep = sol.deploy(global_batch=batch)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    steps = dep.build_steps(mesh, batch=batch, max_len=max_len)[cfg.name]
+    params = _shaped(mesh, steps["param_specs"],
+                     lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    return cfg, mesh, steps, params
+
+
+def _shaped(mesh, specs, make):
+    """Shapes of ``make()``'s pytree, each with its sharding from ``specs``."""
+    return jax.tree.map(
+        lambda s, x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        to_shardings(mesh, specs), jax.eval_shape(make))
+
+
+def _hbm_bytes(compiled):
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.temp_size_in_bytes
+
+
+def test_decode_step_fits_one_chip(one_chip):
+    batch, max_len = 8, 1024
+    cfg, mesh, steps, params = _granite_steps(one_chip, batch, max_len)
+    caches = _shaped(mesh, steps["cache_specs"],
+                     lambda: init_kv_cache(cfg, batch, max_len))
+    dp = steps["plan"].dp
+    tok = _spec(NamedSharding(mesh, P(dp, None)), (batch, 1), jnp.int32)
+    pos = _spec(NamedSharding(mesh, P(dp)), (batch,), jnp.int32)
+    compiled = steps["decode"].lower(params, tok, pos, caches).compile()
+    assert 8 * 10**9 < _hbm_bytes(compiled) < HBM_BYTES
+
+
+def test_prefill_step_fits_one_chip(one_chip):
+    batch, seq = 8, 512
+    _, mesh, steps, params = _granite_steps(one_chip, batch, 1024)
+    toks = _spec(NamedSharding(mesh, P(steps["plan"].dp, None)), (batch, seq),
+                 jnp.int32)
+    compiled = steps["prefill"].lower(params, toks).compile()
+    assert 8 * 10**9 < _hbm_bytes(compiled) < HBM_BYTES
