@@ -1,0 +1,7 @@
+"""Model FLOPs the window's prefill work needs, over window wall time x chips x
+the bf16 peak."""
+from readers import mfu
+
+
+def read(ctx):
+    return mfu(ctx, "prefill")
