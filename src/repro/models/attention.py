@@ -97,9 +97,10 @@ def attention_decode(
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
 
-    bidx = jnp.arange(B)
-    cache_k = cache_k.at[bidx, position].set(k[:, 0])
-    cache_v = cache_v.at[bidx, position].set(v[:, 0])
+    with jax.named_scope("kv_write"):
+        bidx = jnp.arange(B)
+        cache_k = cache_k.at[bidx, position].set(k[:, 0])
+        cache_v = cache_v.at[bidx, position].set(v[:, 0])
 
     scores = attention_scores(q, cache_k, hd ** -0.5, cfg.attn_softcap)  # [B,H,1,T]
     j = jnp.arange(T)[None, :]

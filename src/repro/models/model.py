@@ -11,6 +11,13 @@ small and compile times sane at 48+ layers).  To execute a Scope schedule,
   under ``constrain2``.  Implemented as two scan segments over sliced
   stacked params -- per-layer heterogeneous sharding with scanned layers is
   exactly what the single-transition-point structure makes possible.
+
+The parts of a step run under fixed ``jax.named_scope`` names, which reach
+the compiled program only as ``op_name`` metadata: ``embed``, ``layers``
+(the scan over the block stack), per block the sequence mixer ``attn`` /
+``mamba`` / ``rwkv`` (``attn/kv_write`` around the decode cache write) and
+the channel mixer ``ffn`` / ``moe``, then ``head``.  The names are part of
+the interface: device time is split by them.
 """
 from __future__ import annotations
 
@@ -89,39 +96,38 @@ def param_count(params) -> int:
 
 # ------------------------------------------------------------------ forward
 
+def _mixer_scope(kind: str) -> str:
+    """Named scope (and constrain tag) of a block kind's sequence mixer."""
+    if kind in ("attn", "local"):
+        return "attn"
+    if kind in ("mamba", "rwkv"):
+        return kind
+    raise ValueError(kind)
+
+
 def _block_prefill(cfg, kind, layer_idx_in_pattern, bp, x, positions, constrain):
     tag = f"blk{layer_idx_in_pattern}"
-    h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
-    if kind in ("attn", "local"):
-        window = cfg.window if kind == "local" else 0
-        a, kv = attention_prefill(bp["attn"], h, cfg, positions, window)
-        x = constrain(x + a, f"{tag}:attn")
-        h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        if "moe" in bp:
-            f = moe_ffn(bp["moe"], h2, cfg, constrain)
+    mixer = _mixer_scope(kind)
+    with jax.named_scope(mixer):
+        h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
+        if mixer == "attn":
+            window = cfg.window if kind == "local" else 0
+            a, kv = attention_prefill(bp["attn"], h, cfg, positions, window)
+            cache = {"k": kv[0], "v": kv[1]}
+        elif mixer == "mamba":
+            a, cache = mamba_prefill(bp["mamba"], h, cfg)
         else:
-            f = ffn(bp["ffn"], h2, cfg.ffn_gated)
-        x = constrain(x + f, f"{tag}:ffn")
-        cache = {"k": kv[0], "v": kv[1]}
-    elif kind == "mamba":
-        a, st = mamba_prefill(bp["mamba"], h, cfg)
-        x = constrain(x + a, f"{tag}:mamba")
+            a, cache = rwkv_time_mix(bp["rwkv"], h, cfg)
+        x = constrain(x + a, f"{tag}:{mixer}")
+    with jax.named_scope("moe" if "moe" in bp else "ffn"):
         h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        if "moe" in bp:
-            f = moe_ffn(bp["moe"], h2, cfg, constrain)
+        if mixer == "rwkv":
+            f, st2 = rwkv_channel_mix(bp["rwkv"], h2)
+            cache = {**cache, **st2}
         else:
-            f = ffn(bp["ffn"], h2, cfg.ffn_gated)
+            f = (moe_ffn(bp["moe"], h2, cfg, constrain) if "moe" in bp
+                 else ffn(bp["ffn"], h2, cfg.ffn_gated))
         x = constrain(x + f, f"{tag}:ffn")
-        cache = st
-    elif kind == "rwkv":
-        a, st = rwkv_time_mix(bp["rwkv"], h, cfg)
-        x = constrain(x + a, f"{tag}:rwkv")
-        h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        f, st2 = rwkv_channel_mix(bp["rwkv"], h2)
-        x = constrain(x + f, f"{tag}:ffn")
-        cache = {**st, **st2}
-    else:
-        raise ValueError(kind)
     return x, cache
 
 
@@ -156,44 +162,55 @@ def forward(
     positions: jax.Array | None = None,
 ):
     """Returns (logits [B,S,V], caches or None)."""
-    if cfg.frontend == "audio_stub":
-        x = frontend_embeds.astype(jnp.dtype(cfg.param_dtype))
-        B, S = x.shape[:2]
-    elif cfg.frontend == "vision_stub":
-        t_emb = embed(tokens, params["embed"])
-        x = jnp.concatenate(
-            [frontend_embeds.astype(t_emb.dtype), t_emb], axis=1
-        )
-        B, S = x.shape[:2]
-    else:
-        x = embed(tokens, params["embed"])
-        B, S = tokens.shape
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    x = constrain(x, "embed")
+    with jax.named_scope("embed"):
+        if cfg.frontend == "audio_stub":
+            x = frontend_embeds.astype(jnp.dtype(cfg.param_dtype))
+            B, S = x.shape[:2]
+        elif cfg.frontend == "vision_stub":
+            t_emb = embed(tokens, params["embed"])
+            x = jnp.concatenate(
+                [frontend_embeds.astype(t_emb.dtype), t_emb], axis=1
+            )
+            B, S = x.shape[:2]
+        else:
+            x = embed(tokens, params["embed"])
+            B, S = tokens.shape
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        x = constrain(x, "embed")
 
-    if transition_repeat is None or constrain2 is None:
-        blocks = params["blocks"]
-        x, caches = _scan_blocks(cfg, blocks, x, positions, constrain, collect_cache)
-    else:
-        t = transition_repeat
-        zone1 = jax.tree.map(lambda a: a[:t], params["blocks"])
-        zone2 = jax.tree.map(lambda a: a[t:], params["blocks"])
-        caches = []
-        if t > 0:
-            x, c1 = _scan_blocks(cfg, zone1, x, positions, constrain, collect_cache)
-            caches.append(c1)
-        if t < cfg.pattern_repeats:
-            x = constrain2(x, "transition")
-            x, c2 = _scan_blocks(cfg, zone2, x, positions, constrain2, collect_cache)
-            caches.append(c2)
-        caches = tuple(caches) if collect_cache else None
+    with jax.named_scope("layers"):
+        if transition_repeat is None or constrain2 is None:
+            blocks = params["blocks"]
+            x, caches = _scan_blocks(cfg, blocks, x, positions, constrain,
+                                     collect_cache)
+        else:
+            t = transition_repeat
+            zone1 = jax.tree.map(lambda a: a[:t], params["blocks"])
+            zone2 = jax.tree.map(lambda a: a[t:], params["blocks"])
+            caches = []
+            if t > 0:
+                x, c1 = _scan_blocks(cfg, zone1, x, positions, constrain,
+                                     collect_cache)
+                caches.append(c1)
+            if t < cfg.pattern_repeats:
+                x = constrain2(x, "transition")
+                x, c2 = _scan_blocks(cfg, zone2, x, positions, constrain2,
+                                     collect_cache)
+                caches.append(c2)
+            caches = tuple(caches) if collect_cache else None
 
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = dense(x, head)
-    logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
-    return constrain(logits, "logits"), caches
+    return _head(params, cfg, x, constrain), caches
+
+
+def _head(params, cfg, x, constrain):
+    """Final norm, lm head, float32 logits, softcap."""
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = dense(x, head)
+        logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
+        return constrain(logits, "logits")
 
 
 # ----------------------------------------------------------------- KV cache
@@ -226,30 +243,28 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 
 def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain):
     tag = f"blk{pi}"
-    h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
-    if kind in ("attn", "local"):
-        window = cfg.window if kind == "local" else 0
-        a, (ck, cv) = attention_decode(
-            bp["attn"], h, cfg, cache["k"], cache["v"], position, window
-        )
-        new_cache = {"k": ck, "v": cv}
-        x = constrain(x + a, f"{tag}:attn")
+    mixer = _mixer_scope(kind)
+    with jax.named_scope(mixer):
+        h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
+        if mixer == "attn":
+            window = cfg.window if kind == "local" else 0
+            a, (ck, cv) = attention_decode(
+                bp["attn"], h, cfg, cache["k"], cache["v"], position, window
+            )
+            new_cache = {"k": ck, "v": cv}
+        elif mixer == "mamba":
+            a, new_cache = mamba_decode(bp["mamba"], h, cfg, cache)
+        else:
+            a, new_cache = rwkv_time_mix(bp["rwkv"], h, cfg, state=cache)
+        x = constrain(x + a, f"{tag}:{mixer}")
+    with jax.named_scope("moe" if "moe" in bp else "ffn"):
         h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        f = moe_ffn(bp["moe"], h2, cfg, constrain) if "moe" in bp else ffn(bp["ffn"], h2, cfg.ffn_gated)
-        x = constrain(x + f, f"{tag}:ffn")
-    elif kind == "mamba":
-        a, st = mamba_decode(bp["mamba"], h, cfg, cache)
-        new_cache = st
-        x = constrain(x + a, f"{tag}:mamba")
-        h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        f = moe_ffn(bp["moe"], h2, cfg, constrain) if "moe" in bp else ffn(bp["ffn"], h2, cfg.ffn_gated)
-        x = constrain(x + f, f"{tag}:ffn")
-    elif kind == "rwkv":
-        a, st = rwkv_time_mix(bp["rwkv"], h, cfg, state=cache)
-        x = constrain(x + a, f"{tag}:rwkv")
-        h2 = rmsnorm(x, bp["ln2"], cfg.norm_eps)
-        f, st2 = rwkv_channel_mix(bp["rwkv"], h2, state=cache)
-        new_cache = {**st, **st2}
+        if mixer == "rwkv":
+            f, st2 = rwkv_channel_mix(bp["rwkv"], h2, state=cache)
+            new_cache = {**new_cache, **st2}
+        else:
+            f = (moe_ffn(bp["moe"], h2, cfg, constrain) if "moe" in bp
+                 else ffn(bp["ffn"], h2, cfg.ffn_gated))
         x = constrain(x + f, f"{tag}:ffn")
     return x, new_cache
 
@@ -263,8 +278,9 @@ def decode_step(
     constrain=_identity_constrain,
 ):
     """One autoregressive step.  Returns (logits [B,1,V], new caches)."""
-    x = embed(token, params["embed"])
-    x = constrain(x, "embed")
+    with jax.named_scope("embed"):
+        x = embed(token, params["embed"])
+        x = constrain(x, "embed")
 
     def body(carry, scanned):
         h = carry
@@ -276,15 +292,12 @@ def decode_step(
             new_caches.append(nc)
         return h, tuple(new_caches)
 
-    x, new_caches = jax.lax.scan(
-        body, x, (params["blocks"], caches),
-        unroll=max(1, min(cfg.scan_unroll, cfg.pattern_repeats)),
-    )
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = dense(x, head)
-    logits = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
-    return constrain(logits, "logits"), new_caches
+    with jax.named_scope("layers"):
+        x, new_caches = jax.lax.scan(
+            body, x, (params["blocks"], caches),
+            unroll=max(1, min(cfg.scan_unroll, cfg.pattern_repeats)),
+        )
+    return _head(params, cfg, x, constrain), new_caches
 
 
 # -------------------------------------------------------------------- loss

@@ -22,7 +22,6 @@ from .trace import (
     NullTracer,
     Tracer,
     current_tracer,
-    traced,
     use_tracer,
     validate_chrome_trace,
 )
@@ -40,7 +39,6 @@ __all__ = [
     "Tracer",
     "current_tracer",
     "render_dashboard",
-    "traced",
     "use_tracer",
     "validate_chrome_trace",
     "write_dashboard",

@@ -25,7 +25,6 @@ and pays roughly a dict-free method call per span when tracing is off —
 """
 from __future__ import annotations
 
-import functools
 import json
 import time
 
@@ -36,7 +35,6 @@ __all__ = [
     "NullTracer",
     "Tracer",
     "current_tracer",
-    "traced",
     "use_tracer",
     "validate_chrome_trace",
 ]
@@ -120,21 +118,6 @@ class use_tracer:
     def __exit__(self, exc_type, exc, tb):
         _STACK.pop()
         return False
-
-
-def traced(name: str | None = None, group: str = "dse", lane: str = "solver"):
-    """Decorator: run the function inside a span on the ambient tracer."""
-    def deco(fn):
-        label = name or fn.__name__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with current_tracer().span(label, group=group, lane=lane):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
 
 
 # ---------------------------------------------------------------------------

@@ -31,7 +31,6 @@ from repro.obs import (
     TimeSeries,
     Tracer,
     current_tracer,
-    traced,
     use_tracer,
     validate_chrome_trace,
 )
@@ -172,17 +171,6 @@ class TestNullOverhead:
                 assert current_tracer() is NULL_TRACER
             assert current_tracer() is tr
         assert current_tracer() is NULL_TRACER
-
-    def test_traced_decorator_uses_ambient_tracer(self):
-        @traced("unit", group="dse", lane="solver")
-        def f(x):
-            return x + 1
-
-        assert f(1) == 2                 # disabled: plain call
-        tr = Tracer()
-        with use_tracer(tr):
-            assert f(2) == 3
-        assert [e[1] for e in tr.events] == ["unit"]
 
 
 # ---------------------------------------------------------------------------
