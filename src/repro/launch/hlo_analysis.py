@@ -4,7 +4,8 @@
 HLO text: every ``all-gather``/``all-reduce``/``reduce-scatter``/
 ``all-to-all``/``collective-permute`` op contributes its operand bytes.
 Shapes are parsed from the HLO result/operand types (e.g.
-``bf16[2,4096,128]{...}``).
+``bf16[2,4096,128]{...}``).  ``unfused_instructions`` lists the ops a
+program runs, each with its result type and ``op_name``.
 """
 from __future__ import annotations
 
@@ -30,7 +31,7 @@ _OP_RE = re.compile(
 )
 
 
-def _shape_bytes(type_str: str) -> int:
+def shape_bytes(type_str: str) -> int:
     total = 0
     for m in _SHAPE_RE.finditer(type_str):
         dt, dims = m.group(1), m.group(2)
@@ -68,10 +69,57 @@ def collective_stats(hlo_text: str) -> CollectiveStats:
         line = hlo_text[m.start():hlo_text.index("\n", m.start())]
         if f"{kind}-done" in line:
             continue
-        b = _shape_bytes(type_str)
+        b = shape_bytes(type_str)
         stats.bytes_by_kind[kind] = stats.bytes_by_kind.get(kind, 0) + b
         stats.count_by_kind[kind] = stats.count_by_kind.get(kind, 0) + 1
     return stats
+
+
+# -------------------------------------------------------------- instructions
+
+_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+([a-z][\w\-]*)\("
+)
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_ARRAY_RE = re.compile(r"^\w+\[([\d,]*)\]")
+
+# opcodes that pass an array on (or name one) without writing it
+PASS_THROUGH = frozenset(
+    {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+)
+
+
+@dataclass(frozen=True)
+class Instr:
+    name: str
+    opcode: str
+    type: str          # result type, e.g. "bf16[16,2048,8,128]{...}"
+    op_name: str       # "" where the instruction carries none
+
+    @property
+    def dims(self) -> tuple[int, ...] | None:
+        """The result's dimensions; None where the result is a tuple."""
+        m = _ARRAY_RE.match(self.type)
+        return tuple(int(d) for d in m.group(1).split(",") if d) if m else None
+
+
+def unfused_instructions(hlo_text: str) -> list[Instr]:
+    """Every instruction outside the computations that fusions call: the
+    ops the program runs, a fusion counted once under its own op_name."""
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo_text))
+    out, inside = [], False
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION_RE.match(line)
+        if head:
+            inside = head.group(1) in fused
+            continue
+        m = _INSTR_RE.match(line)
+        if m and not inside:
+            on = _OP_NAME_RE.search(line)
+            out.append(Instr(m.group(1), m.group(3), m.group(2),
+                             on.group(1) if on else ""))
+    return out
 
 
 # ------------------------------------------------------------------ roofline

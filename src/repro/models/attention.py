@@ -80,16 +80,21 @@ def attention_decode(
     params: dict,
     x: jax.Array,
     cfg: ModelConfig,
-    cache_k: jax.Array,        # [B, T, KV, hd]
+    cache_k: jax.Array,        # [B, T, KV, hd], or [R, B, T, KV, hd] with layer
     cache_v: jax.Array,
     position: jax.Array,       # [B] current write index
     window: int = 0,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
-    """One-token decode against a KV cache, in-place cache update."""
+    """One-token decode against a KV cache, in-place cache update.
+
+    With ``layer`` the caches are the stack of every repeat's cache: only
+    row ``[layer, b, position[b]]`` is written, attention reads layer
+    ``layer`` of the stack, and the stacks are returned."""
     B, S1, _ = x.shape
     assert S1 == 1
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    T = cache_k.shape[1]
+    T = cache_k.shape[-3]
     q = _split_heads(dense(x, params["wq"]), H, hd)
     k = _split_heads(dense(x, params["wk"]), KV, hd)
     v = _split_heads(dense(x, params["wv"]), KV, hd)
@@ -98,11 +103,18 @@ def attention_decode(
     k = apply_rope(k, cos, sin)
 
     with jax.named_scope("kv_write"):
-        bidx = jnp.arange(B)
-        cache_k = cache_k.at[bidx, position].set(k[:, 0])
-        cache_v = cache_v.at[bidx, position].set(v[:, 0])
+        row = (jnp.arange(B), position)
+        if layer is not None:
+            row = (layer, *row)
+        cache_k = cache_k.at[row].set(k[:, 0])
+        cache_v = cache_v.at[row].set(v[:, 0])
+    if layer is None:
+        layer_k, layer_v = cache_k, cache_v
+    else:
+        layer_k = jax.lax.dynamic_index_in_dim(cache_k, layer, keepdims=False)
+        layer_v = jax.lax.dynamic_index_in_dim(cache_v, layer, keepdims=False)
 
-    scores = attention_scores(q, cache_k, hd ** -0.5, cfg.attn_softcap)  # [B,H,1,T]
+    scores = attention_scores(q, layer_k, hd ** -0.5, cfg.attn_softcap)  # [B,H,1,T]
     j = jnp.arange(T)[None, :]
     valid = j <= position[:, None]
     if window > 0:
@@ -111,7 +123,7 @@ def attention_decode(
     probs = jax.nn.softmax(scores, axis=-1)
     g = H // KV
     out = jnp.einsum(
-        "bkgst,btkh->bskgh", probs.reshape(B, KV, g, 1, T), cache_v.astype(jnp.float32)
+        "bkgst,btkh->bskgh", probs.reshape(B, KV, g, 1, T), layer_v.astype(jnp.float32)
     )
     out = out.reshape(B, 1, H * hd).astype(x.dtype)
     return dense(out, params["wo"]), (cache_k, cache_v)

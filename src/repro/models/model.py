@@ -18,6 +18,11 @@ the compiled program only as ``op_name`` metadata: ``embed``, ``layers``
 ``mamba`` / ``rwkv`` (``attn/kv_write`` around the decode cache write) and
 the channel mixer ``ffn`` / ``moe``, then ``head``.  The names are part of
 the interface: device time is split by them.
+
+In ``decode_step`` the stacked caches ride in the scan's carry: attention
+writes its new row into the stack under ``attn/kv_write`` and reads its
+layer straight from the stack, so ``layers`` slices only the stacked
+weights (and the small per-layer mamba / rwkv states) and stacks nothing.
 """
 from __future__ import annotations
 
@@ -241,15 +246,25 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
     return tuple(caches)
 
 
-def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain):
+def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain, layer=None):
+    """One block's decode step.  ``cache`` is this layer's own cache, or,
+    with ``layer``, the stack of every repeat's cache at this pattern
+    position: attention then writes its new row into the stack in place,
+    and a state layer reads its state out and writes it back whole."""
     tag = f"blk{pi}"
     mixer = _mixer_scope(kind)
+    stacked = layer is not None
+    if stacked and mixer != "attn":
+        stack, cache = cache, jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            cache)
     with jax.named_scope(mixer):
         h = rmsnorm(x, bp["ln1"], cfg.norm_eps)
         if mixer == "attn":
             window = cfg.window if kind == "local" else 0
             a, (ck, cv) = attention_decode(
-                bp["attn"], h, cfg, cache["k"], cache["v"], position, window
+                bp["attn"], h, cfg, cache["k"], cache["v"], position, window,
+                layer,
             )
             new_cache = {"k": ck, "v": cv}
         elif mixer == "mamba":
@@ -266,6 +281,13 @@ def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain):
             f = (moe_ffn(bp["moe"], h2, cfg, constrain) if "moe" in bp
                  else ffn(bp["ffn"], h2, cfg.ffn_gated))
         x = constrain(x + f, f"{tag}:ffn")
+    if stacked and mixer != "attn":
+        new_cache = {
+            name: jax.lax.dynamic_update_index_in_dim(
+                stack[name], new_cache[name].astype(stack[name].dtype),
+                layer, 0)
+            for name in stack
+        }
     return x, new_cache
 
 
@@ -277,25 +299,32 @@ def decode_step(
     caches: tuple,
     constrain=_identity_constrain,
 ):
-    """One autoregressive step.  Returns (logits [B,1,V], new caches)."""
+    """One autoregressive step.  Returns (logits [B,1,V], new caches).
+
+    The stacked caches ride in the scan's carry, not in its ``xs``/``ys``:
+    each layer writes its new row into the stack in place, so nothing
+    slices a layer's cache out or stacks the caches again, and the donated
+    input buffers are the output's."""
     with jax.named_scope("embed"):
         x = embed(token, params["embed"])
         x = constrain(x, "embed")
 
     def body(carry, scanned):
-        h = carry
-        bps, layer_caches = scanned
-        new_caches = []
+        h, stacks = carry
+        bps, layer = scanned
+        new_stacks = []
         for pi, kind in enumerate(cfg.expanded_pattern):
-            h, nc = _block_decode(cfg, kind, pi, bps[pi], h, position,
-                                  layer_caches[pi], constrain)
-            new_caches.append(nc)
-        return h, tuple(new_caches)
+            h, c = _block_decode(cfg, kind, pi, bps[pi], h, position,
+                                 stacks[pi], constrain, layer)
+            new_stacks.append(c)
+        return (h, tuple(new_stacks)), None
 
+    R = cfg.pattern_repeats
     with jax.named_scope("layers"):
-        x, new_caches = jax.lax.scan(
-            body, x, (params["blocks"], caches),
-            unroll=max(1, min(cfg.scan_unroll, cfg.pattern_repeats)),
+        (x, new_caches), _ = jax.lax.scan(
+            body, (x, tuple(caches)),
+            (params["blocks"], jnp.arange(R, dtype=jnp.int32)),
+            unroll=max(1, min(cfg.scan_unroll, R)),
         )
     return _head(params, cfg, x, constrain), new_caches
 

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.launch.hlo_analysis import PASS_THROUGH, unfused_instructions
 from repro.launch.mesh import single_device_mesh
 from repro.models import attention, init_kv_cache, init_params, model
 from repro.runtime.serve import build_decode_step, build_prefill_step
@@ -84,15 +85,27 @@ def test_every_matmul_is_in_a_block_or_the_head(hlo, kind):
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_scan_slicing_is_under_layers(hlo, kind):
-    """The scan's own slicing of the stacked weights (and, in decode, its
-    update of the stacked cache) runs under ``layers``, outside any block."""
+    """The scan's own slicing of the stacked weights runs under ``layers``,
+    outside any block.  In decode the stacked cache rides in the scan's
+    carry: nothing updates a slice of it, and the only ops that write an
+    array of its shape are the cache write's under ``attn/kv_write``."""
     scan = [on for _, _, on in _ops(hlo[kind])
             if re.search(r"/while/body/dynamic_(update_)?slice$", on)]
     assert scan
     for on in scan:
         assert "/layers/while/body/" in on, on
     if kind == "decode":
-        assert any(on.endswith("dynamic_update_slice") for on in scan)
+        assert not [(name, on) for name, op, on in _ops(hlo[kind])
+                    if op == "dynamic-update-slice"
+                    or on.endswith("dynamic_update_slice")]
+        cfg = replace(get_smoke_config("granite-3-8b"), n_layers=2)
+        stack = jax.eval_shape(lambda: init_kv_cache(cfg, B, S))[0]["k"].shape
+        writers = [i for i in unfused_instructions(hlo[kind])
+                   if i.dims == stack and i.opcode not in PASS_THROUGH]
+        assert any(i.op_name.endswith("/attn/kv_write/scatter")
+                   for i in writers)
+        for i in writers:
+            assert "/attn/kv_write/" in i.op_name, i
 
 
 def test_decode_cache_write_is_under_attn_kv_write(hlo):

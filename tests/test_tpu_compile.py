@@ -27,6 +27,8 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.mamba.ops import mamba_scan
 from repro.kernels.qmatmul.ops import qmatmul
 from repro.kernels.rwkv6.ops import wkv6
+from repro.launch.hlo_analysis import (PASS_THROUGH, shape_bytes,
+                                      unfused_instructions)
 from repro.models import init_kv_cache, init_params
 from repro.runtime.sharding import to_shardings
 
@@ -97,19 +99,32 @@ def test_qmatmul_compiles(one_chip):
 
 # ---------------------------------------------------------- main-path steps
 
-def _granite_steps(one_chip, batch, max_len):
-    """granite-3-8b cut to 20 of 40 layers, solved on a one-chip package
-    and built through deploy on a (1, 1) mesh of the described chip."""
-    cfg = replace(get_config("granite-3-8b"), n_layers=20)
+def _granite_steps(devices, package, batch, max_len, n_layers=20):
+    """granite-3-8b cut to ``n_layers`` of 40 layers, solved on ``package``
+    and built through deploy on a (1, chips) mesh of the described chips."""
+    cfg = replace(get_config("granite-3-8b"), n_layers=n_layers)
     sol = scope.solve(scope.problem(scope.WorkloadSpec.lm([cfg], 512),
-                                    tpu_v5e(1, (1, 1))))
+                                    package))
     dep = sol.deploy(global_batch=batch)
-    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+    mesh = Mesh(np.array(devices).reshape(1, len(devices)),
                 ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     steps = dep.build_steps(mesh, batch=batch, max_len=max_len)[cfg.name]
     params = _shaped(mesh, steps["param_specs"],
                      lambda: init_params(cfg, jax.random.PRNGKey(0)))
     return cfg, mesh, steps, params
+
+
+def _decode_compiled(devices, package, batch, max_len, n_layers=20):
+    """(stacked K cache shape, compiled decode step) of ``_granite_steps``."""
+    cfg, mesh, steps, params = _granite_steps(devices, package, batch,
+                                              max_len, n_layers)
+    caches = _shaped(mesh, steps["cache_specs"],
+                     lambda: init_kv_cache(cfg, batch, max_len))
+    dp = steps["plan"].dp
+    tok = _spec(NamedSharding(mesh, P(dp, None)), (batch, 1), jnp.int32)
+    pos = _spec(NamedSharding(mesh, P(dp)), (batch,), jnp.int32)
+    compiled = steps["decode"].lower(params, tok, pos, caches).compile()
+    return caches[0]["k"].shape, compiled
 
 
 def _shaped(mesh, specs, make):
@@ -124,21 +139,59 @@ def _hbm_bytes(compiled):
     return m.argument_size_in_bytes + m.temp_size_in_bytes
 
 
-def test_decode_step_fits_one_chip(one_chip):
-    batch, max_len = 8, 1024
-    cfg, mesh, steps, params = _granite_steps(one_chip, batch, max_len)
-    caches = _shaped(mesh, steps["cache_specs"],
-                     lambda: init_kv_cache(cfg, batch, max_len))
-    dp = steps["plan"].dp
-    tok = _spec(NamedSharding(mesh, P(dp, None)), (batch, 1), jnp.int32)
-    pos = _spec(NamedSharding(mesh, P(dp)), (batch,), jnp.int32)
-    compiled = steps["decode"].lower(params, tok, pos, caches).compile()
+@pytest.fixture(scope="module")
+def cell_decode(one_chip):
+    """The decode step of the one-chip decode cell: granite-3-8b cut to 20
+    layers, 16 slots of 2048 positions."""
+    return _decode_compiled(list(one_chip.device_set), tpu_v5e(1, (1, 1)),
+                            16, 2048)
+
+
+def test_decode_step_fits_one_chip(cell_decode):
+    _, compiled = cell_decode
     assert 8 * 10**9 < _hbm_bytes(compiled) < HBM_BYTES
+
+
+def test_decode_writes_the_stacked_cache_in_place(cell_decode):
+    """Decode writes each new K/V row into the donated stacked cache: no op
+    copies or updates the stack or a whole layer of it, save the scatter of
+    the new rows under ``attn/kv_write`` and attention's read of its layer,
+    and the step needs next to no scratch memory (2.82 GB when the scan
+    sliced and restacked the caches)."""
+    stack, compiled = cell_decode
+    layer = stack[1:]
+    big = [i for i in unfused_instructions(compiled.as_text())
+           if i.dims in (stack, layer, (1, *layer))
+           and i.opcode not in PASS_THROUGH]
+    assert any(i.dims == stack and i.op_name.endswith("/attn/kv_write/scatter")
+               for i in big)
+    for i in big:
+        assert i.opcode != "copy" and "dynamic-update-slice" not in i.name, i
+        assert "/attn/" in i.op_name, i
+        if i.dims == stack:
+            assert "/attn/kv_write/" in i.op_name, i
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 10**9
+
+
+def test_sharded_decode_gathers_no_cache(topo):
+    """granite-3-8b (40 layers) decoding on the described 2x2 package under
+    the plan ``scope.solve`` gives it: the carried caches keep their
+    shards, so no all-gather or all-to-all moves a layer of cache."""
+    stack, compiled = _decode_compiled(topo.devices, tpu_v5e(4, (2, 2)), 16,
+                                       2048, n_layers=40)
+    layer_shard = np.prod(stack[1:]) * 2 // len(topo.devices)   # bf16
+    ops = unfused_instructions(compiled.as_text())
+    moves = [i for i in ops
+             if i.opcode.removesuffix("-start") in ("all-gather", "all-to-all")]
+    assert any(i.opcode.startswith("all-") for i in ops)
+    for i in moves:
+        assert shape_bytes(i.type) < layer_shard, i
 
 
 def test_prefill_step_fits_one_chip(one_chip):
     batch, seq = 8, 512
-    _, mesh, steps, params = _granite_steps(one_chip, batch, 1024)
+    _, mesh, steps, params = _granite_steps(list(one_chip.device_set),
+                                            tpu_v5e(1, (1, 1)), batch, 1024)
     toks = _spec(NamedSharding(mesh, P(steps["plan"].dp, None)), (batch, seq),
                  jnp.int32)
     compiled = steps["prefill"].lower(params, toks).compile()
