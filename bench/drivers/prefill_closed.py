@@ -19,8 +19,7 @@ import time
 import numpy as np
 
 from gen import Stream, bucket_of, rng, tokens
-from harness import (hlo_module_name, init_weights, log, program_config,
-                     solve_and_build)
+from harness import arch, hlo_module_name, init_weights, log, solve_and_build
 
 
 def _identity(name, fn):
@@ -34,7 +33,7 @@ def setup(cell, fault=None):
 
     fault = fault or _identity
     tr, cfg = cell.traffic, cell.cfg
-    mc = program_config(cell.config)
+    mc = arch(cell).program_config(cell.config)
     V = cfg["vocab_size"]
     mesh, steps = solve_and_build(cell, mc, "prefill", tr["plan_seq_len"],
                                   tr["plan_batch"], None, False)

@@ -5,6 +5,8 @@ per-layer metric is a file of its own, found by the name that
 ``BENCHMARK.json`` gives it:
 
 * ``bench/configs/<config>.json``  -- sizes as run, source, cut, deployment;
+* ``bench/archs/<model_type>.py``  -- what depends on the architecture: the
+  program's ModelConfig, the plain reference and the counts (``arch``);
 * ``bench/traffic/<traffic>.json`` -- a mix, read by ``gen.py`` and the
   driver the mix names (``bench/drivers/<driver>.py``);
 * ``bench/metrics/<metric>.py``    -- ``read(ctx)`` of one per-layer metric;
@@ -16,12 +18,14 @@ on-device weights (``runtime.serve.init_sharded_params``).
 """
 from __future__ import annotations
 
+import functools
 import gc
 import importlib.util
 import json
+import re
 import sys
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -36,6 +40,7 @@ def load_module(path: Path):
     spec = importlib.util.spec_from_file_location(
         "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
     spec.loader.exec_module(mod)
     return mod
 
@@ -74,33 +79,23 @@ class Cell:
 
 # --------------------------------------------------------------- the program
 
-def program_config(config: dict):
-    """The program's ModelConfig with the sizes of a configuration file;
-    refused where the program would compute another model than the file
-    states (it has no keys for Granite's multipliers)."""
-    import jax.numpy as jnp
+def arch(cell: Cell):
+    """The module of ``bench/archs`` named by the configuration's
+    ``config.model_type``: ``program_config(config)`` (the program's
+    ModelConfig), ``forward_rows(cfg, key, seqs, rows, quant=None)`` (the
+    plain reference) and ``work(cfg, window)`` (FLOPs and bytes of each
+    step).  Exits, naming the file it looked for, where there is none."""
+    name = cell.cfg.get("model_type", "")
+    path = BENCH / "archs" / f"{name}.py"
+    if not re.fullmatch(r"[A-Za-z0-9_]+", name) or not path.is_file():
+        raise SystemExit(f"{cell.config['name']}: no architecture file "
+                         f"{path} for model_type {name!r}")
+    return _load_arch(path)
 
-    from repro.configs import get_config
 
-    c = config["config"]
-    mc = replace(
-        get_config(config["program_arch"]), n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab=c["vocab_size"], rope_theta=c["rope_theta"],
-        norm_eps=c["rms_norm_eps"], ffn_gated=c["hidden_act"] == "silu",
-        tie_embeddings=c["tie_word_embeddings"], param_dtype=config["dtype"])
-    fixed = {"attention_multiplier": mc.head_dim ** -0.5,
-             "embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-             "logits_scaling": 1.0}
-    bad = {k: (v, c[k]) for k, v in fixed.items()
-           if abs(c[k] - v) > 1e-12 * max(1.0, abs(v))}
-    if bad or mc.block_pattern != ("attn",) or mc.moe is not None \
-            or mc.logit_softcap or mc.attn_softcap or mc.d_head \
-            or jnp.dtype(mc.param_dtype) != jnp.bfloat16:
-        raise SystemExit(f"{config['name']}: the program computes another "
-                         f"model than the file states (program, file): {bad}")
-    return mc
+@functools.cache
+def _load_arch(path: Path):
+    return load_module(path)
 
 
 def solve_and_build(cell: Cell, mc, phase: str, seq_len: int, batch: int,

@@ -1,5 +1,6 @@
 """Share of the roofline of the prefill program: the least time of the work the
-model needs (bench/counts.py) over the program's device time."""
+model needs (``work`` of bench/archs/<model_type>.py) over the program's
+device time."""
 from readers import roofline
 
 

@@ -7,16 +7,21 @@ device's row of peaks.json) and ``chips``.
 """
 from __future__ import annotations
 
-import counts
+from harness import arch
 
 
 def work(ctx) -> list[tuple[float, float]]:
-    """(FLOPs, bytes) the model needs for each step or call of the window."""
-    s = counts.Shapes.of(ctx["cell"].cfg)
-    w = ctx["window"]
-    if w["kind"] == "decode":
-        return [counts.decode_step(s, pos) for pos in w["step_pos"]]
-    return [counts.prefill_call(s, lengths) for _, lengths in w["calls"]]
+    """(FLOPs, bytes) the model needs for each step or call of the window,
+    by the architecture's counts (``work`` of ``bench/archs``)."""
+    cell = ctx["cell"]
+    return arch(cell).work(cell.cfg, ctx["window"])
+
+
+def least_time(flops: float, nbytes: float, peak_flops: float,
+               peak_bw: float) -> tuple[float, str]:
+    """The roofline: the larger of compute time and memory time, and which."""
+    tc, tm = flops / peak_flops, nbytes / peak_bw
+    return (tc, "compute") if tc >= tm else (tm, "memory")
 
 
 def roofline(ctx, kind: str):
@@ -29,8 +34,8 @@ def roofline(ctx, kind: str):
     if not runs or dev_s <= 0:
         return None
     pk = ctx["peaks"]
-    least = [counts.least_time(f, b, pk["bf16_flops_per_s"] * ctx["chips"],
-                               pk["hbm_bytes_per_s"] * ctx["chips"])[0]
+    least = [least_time(f, b, pk["bf16_flops_per_s"] * ctx["chips"],
+                        pk["hbm_bytes_per_s"] * ctx["chips"])[0]
              for f, b in work(ctx)]
     # mean over host-counted steps against mean over traced executions
     return 100.0 * (sum(least) / len(least)) / (dev_s / runs)

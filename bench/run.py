@@ -4,9 +4,11 @@
 
 Run from the root of a checkout that holds ``BENCHMARK.json``, ``bench/``
 and the program under ``src/``.  It uses the chips of the machine it runs
-on and exits non-zero, printing no result, where JAX finds no TPU, fewer
-chips than the cell asks for, or a device kind that ``bench/peaks.json``
-does not hold.  Compiled programs are kept in ``<checkout>/.jax_cache``.
+on and exits non-zero, printing no result, where the configuration's
+``model_type`` has no ``bench/archs/<model_type>.py``, JAX finds no TPU,
+the machine has fewer chips than the cell asks for, or its device kind is
+not in ``bench/peaks.json``.  Compiled programs are kept in
+``<checkout>/.jax_cache``.
 
 Progress and measurements go to standard error, whose last lines are each
 number compared beside its limit.  The last line of standard output is the
@@ -51,6 +53,7 @@ def main(argv=None) -> int:
     cell = harness.Cell.find(bench, args.workload, seed=args.seed,
                              seconds=args.seconds, trace=bool(args.trace),
                              t_start=T_START)
+    harness.arch(cell)          # refuses a model_type with no file, here
 
     import jax
 

@@ -38,7 +38,7 @@ import time
 from dataclasses import replace
 
 import devtrace
-from harness import log, program_config, solve_and_build
+from harness import arch, log, solve_and_build
 
 SCOPES = {"embed", "layers", "attn", "kv_write", "mamba", "rwkv", "ffn",
           "moe", "head"}
@@ -256,7 +256,7 @@ def step_hlo(cell, kind: str) -> list[str]:
             lambda s, x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
             to_shardings(mesh, specs), jax.eval_shape(make))
 
-    mc, tr = program_config(cell.config), cell.traffic
+    mc, tr = arch(cell).program_config(cell.config), cell.traffic
     quiet = replace(cell, spans={})        # keep the run's set-up spans
     if kind == "decode":
         B, S = cell.config["decode_slots"], tr["max_len"]
@@ -335,10 +335,16 @@ def _log(kind, t, module, res, build_s, reduce_s):
             log(f"scopes.decode: long step {g}")
 
 
-def scope_ms(ctx, kind: str, bucket: str):
-    """Device ms of ``bucket``'s ops per execution of the step program,
-    averaged over devices (as ``step_ms``)."""
+def scope_ms(ctx, kind: str, bucket: str | None = None, *,
+             scope: str | None = None):
+    """Device ms per execution of the step program, averaged over devices
+    (as ``step_ms``), of ``bucket``'s ops, or of the ops under the one
+    named ``scope`` (every scope path that holds it: ``attn`` takes
+    ``attn/kv_write``); None where the program ran none of them."""
     res = reduce(ctx, kind)
     if not res or not res["runs"]:
         return None
-    return 1e3 * res["buckets"][bucket] / res["runs"]
+    if scope is None:
+        return 1e3 * res["buckets"][bucket] / res["runs"]
+    secs = [v for p, v in res["paths"].items() if scope in p.split("/")]
+    return 1e3 * sum(secs) / res["runs"] if secs else None

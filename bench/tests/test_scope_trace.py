@@ -183,6 +183,22 @@ def test_scope_ms_reads_nothing_without_scopes(monkeypatch):
     assert scopes.scope_ms(c, "prefill", "attn") is None
 
 
+def test_scope_ms_reads_one_scope_by_name(monkeypatch):
+    """One scope from the paths the split collects, beside the buckets: a
+    scope takes every path that holds it, and one that never ran reads
+    nothing."""
+    res = {"runs": 2, "buckets": {"attn": 0.006},
+           "paths": {"attn": 0.004, "attn/kv_write": 0.002, "mamba": 0.01,
+                     "moe": 0.03, "layers": 0.5}}
+    monkeypatch.setattr(scopes, "reduce", lambda ctx, kind: res)
+    assert scopes.scope_ms({}, "decode", "attn") == pytest.approx(3.0)
+    assert scopes.scope_ms({}, "decode", scope="attn") == pytest.approx(3.0)
+    assert scopes.scope_ms({}, "decode", scope="kv_write") == pytest.approx(1.0)
+    assert scopes.scope_ms({}, "decode", scope="mamba") == pytest.approx(5.0)
+    assert scopes.scope_ms({}, "decode", scope="moe") == pytest.approx(15.0)
+    assert scopes.scope_ms({}, "decode", scope="rwkv") is None
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_step_hlo_is_what_the_driver_compiled(kind):
     """The executables rebuilt for the reduction are the driver's own: the
