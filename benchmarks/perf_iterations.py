@@ -123,13 +123,6 @@ def variants_for(arch: str, shape: str, axes, model_axis: int):
             "removes the ZeRO gather collectives",
             variant_plan(base, zero=False), None,
         ))
-    else:
-        out.append((
-            "cache_time_shard=off",
-            "cache replicated over model: no gather at attention, but "
-            "argument bytes x model_axis",
-            variant_plan(base, shard_kv_cache_time=False), None,
-        ))
     return out
 
 

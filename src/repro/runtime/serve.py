@@ -88,7 +88,7 @@ def build_decode_step(cfg: ModelConfig, mesh: Mesh, plan: ShardPlan,
         return decode_step(params, cfg, token, position, caches, constrain=c)
 
     p_specs = _sanitized_param_specs(cfg, plan, mesh)
-    k_specs = cache_pspecs(cfg, plan)
+    k_specs = cache_pspecs(cfg, plan, mesh.shape["model"])
     if batch is not None and max_len is not None:
         cache_shapes = jax.eval_shape(
             lambda: init_kv_cache(cfg, batch, max_len)

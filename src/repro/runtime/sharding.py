@@ -41,7 +41,6 @@ class ShardPlan:
     transition_repeat: int | None = None  # None -> single zone (p1)
     ep: bool = True                       # expert parallelism for MoE weights
     zero: bool = True                     # optimizer state sharded over data too
-    shard_kv_cache_time: bool = True      # decode cache sharded over T
     use_dp: bool = True                   # False when batch < dp size (long_500k)
     # Pipeline stages of the Scope schedule behind this plan, as
     # (layer_lo, layer_hi, chip_type, region_chips) tuples.  On mixed-flavor
@@ -229,15 +228,24 @@ def make_constrain(mesh: Mesh, plan: ShardPlan, zone: int):
 
 # ------------------------------------------------------------------- caches
 
-def cache_pspecs(cfg: ModelConfig, plan: ShardPlan) -> tuple:
+def cache_pspecs(cfg: ModelConfig, plan: ShardPlan, model_size: int) -> tuple:
+    """Specs of the stacked decode caches ``[R, B, T, ...]``.
+
+    K/V ``[R, B, T, KV, hd]`` split over the KV heads where they divide the
+    ``model`` axis (``model_size`` devices): each device then holds the heads
+    its shard of ``wk``/``wv`` computes, so a prompt's K/V or a step's new
+    row is written where it is made, and attention reads only local K/V.
+    Where the heads do not divide the axis, K/V split over positions."""
     dp = plan.dp
-    t_ax = "model" if plan.shard_kv_cache_time else None
+    heads = cfg.n_kv_heads % model_size == 0
+    t_ax = None if heads else "model"
+    h_ax = "model" if heads else None
     specs = []
     for kind in cfg.expanded_pattern:
         if kind in ("attn", "local"):
             specs.append({
-                "k": P(None, dp, t_ax, None, None),
-                "v": P(None, dp, t_ax, None, None),
+                "k": P(None, dp, t_ax, h_ax, None),
+                "v": P(None, dp, t_ax, h_ax, None),
             })
         elif kind == "mamba":
             specs.append({
