@@ -7,6 +7,8 @@ and CPU tests; on real TPUs the Pallas flash kernel
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -85,19 +87,40 @@ def attention_decode(
     position: jax.Array,       # [B] current write index
     window: int = 0,
     layer: jax.Array | None = None,
+    attend=None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """One-token decode against a KV cache, in-place cache update.
 
     With ``layer`` the caches are the stack of every repeat's cache: only
     row ``[layer, b, position[b]]`` is written, attention reads layer
-    ``layer`` of the stack, and the stacks are returned."""
+    ``layer`` of the stack, and the stacks are returned.  ``attend``, where
+    given, places the per-slot part (``attend_cached``) on the devices:
+    ``attend(core, q, k, v, position, cache_k, cache_v, layer)``."""
     B, S1, _ = x.shape
     assert S1 == 1
+    q = dense(x, params["wq"])
+    k = dense(x, params["wk"])
+    v = dense(x, params["wv"])
+    core = partial(attend_cached, cfg, window)
+    if attend is None:
+        out, cache_k, cache_v = core(q, k, v, position, cache_k, cache_v, layer)
+    else:
+        out, cache_k, cache_v = attend(core, q, k, v, position, cache_k,
+                                       cache_v, layer)
+    return dense(out, params["wo"]), (cache_k, cache_v)
+
+
+def attend_cached(cfg: ModelConfig, window: int, q, k, v, position, cache_k,
+                  cache_v, layer):
+    """Decode attention of the slots given: q [B, 1, H*hd] and the new k, v
+    [B, 1, KV*hd] -> (out [B, 1, H*hd], cache_k, cache_v): RoPE, the new
+    rows written into the caches, scores, softmax and P·V."""
+    B = q.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     T = cache_k.shape[-3]
-    q = _split_heads(dense(x, params["wq"]), H, hd)
-    k = _split_heads(dense(x, params["wk"]), KV, hd)
-    v = _split_heads(dense(x, params["wv"]), KV, hd)
+    q = _split_heads(q, H, hd)
+    k = _split_heads(k, KV, hd)
+    v = _split_heads(v, KV, hd)
     cos, sin = rope_freqs(position[:, None], hd, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -125,5 +148,4 @@ def attention_decode(
     out = jnp.einsum(
         "bkgst,btkh->bskgh", probs.reshape(B, KV, g, 1, T), layer_v.astype(jnp.float32)
     )
-    out = out.reshape(B, 1, H * hd).astype(x.dtype)
-    return dense(out, params["wo"]), (cache_k, cache_v)
+    return out.reshape(B, 1, H * hd).astype(q.dtype), cache_k, cache_v

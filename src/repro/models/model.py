@@ -246,11 +246,13 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
     return tuple(caches)
 
 
-def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain, layer=None):
+def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain, layer=None,
+                  attend=None):
     """One block's decode step.  ``cache`` is this layer's own cache, or,
     with ``layer``, the stack of every repeat's cache at this pattern
     position: attention then writes its new row into the stack in place,
-    and a state layer reads its state out and writes it back whole."""
+    and a state layer reads its state out and writes it back whole.
+    ``attend`` is ``attention_decode``'s placement hook."""
     tag = f"blk{pi}"
     mixer = _mixer_scope(kind)
     stacked = layer is not None
@@ -264,7 +266,7 @@ def _block_decode(cfg, kind, pi, bp, x, position, cache, constrain, layer=None):
             window = cfg.window if kind == "local" else 0
             a, (ck, cv) = attention_decode(
                 bp["attn"], h, cfg, cache["k"], cache["v"], position, window,
-                layer,
+                layer, attend,
             )
             new_cache = {"k": ck, "v": cv}
         elif mixer == "mamba":
@@ -298,13 +300,16 @@ def decode_step(
     position: jax.Array,         # [B] write index
     caches: tuple,
     constrain=_identity_constrain,
+    attend=None,
 ):
     """One autoregressive step.  Returns (logits [B,1,V], new caches).
 
     The stacked caches ride in the scan's carry, not in its ``xs``/``ys``:
     each layer writes its new row into the stack in place, so nothing
     slices a layer's cache out or stacks the caches again, and the donated
-    input buffers are the output's."""
+    input buffers are the output's.  ``attend`` places attention's
+    per-slot part on the devices (``attention_decode``), as the runtime
+    chooses for its cache split."""
     with jax.named_scope("embed"):
         x = embed(token, params["embed"])
         x = constrain(x, "embed")
@@ -315,7 +320,7 @@ def decode_step(
         new_stacks = []
         for pi, kind in enumerate(cfg.expanded_pattern):
             h, c = _block_decode(cfg, kind, pi, bps[pi], h, position,
-                                 stacks[pi], constrain, layer)
+                                 stacks[pi], constrain, layer, attend)
             new_stacks.append(c)
         return (h, tuple(new_stacks)), None
 

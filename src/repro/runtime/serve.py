@@ -1,6 +1,9 @@
 """Serving runtime: batched prefill + KV-cache decode steps under a plan."""
 from __future__ import annotations
 
+import math
+import sys
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -10,7 +13,9 @@ from ..models.config import ModelConfig
 from .sharding import (
     ShardPlan,
     cache_pspecs,
+    kv_split,
     make_constrain,
+    over_slots,
     param_pspecs,
     sanitize_pspecs,
     to_shardings,
@@ -81,20 +86,35 @@ def build_decode_step(cfg: ModelConfig, mesh: Mesh, plan: ShardPlan,
     """serve_step: one new token against a resident KV cache (donated).
 
     ``batch``/``max_len`` (when known) let the cache shardings be checked
-    for divisibility against the actual cache shapes."""
+    for divisibility against the actual cache shapes; ``batch`` also lets
+    K/V split over slots (``kv_split``), where attention then runs on each
+    device's own slots (``over_slots``).  The split of each cache entry is
+    logged to stderr."""
     c = make_constrain(mesh, plan, zone=2)   # decode is single-token: ISP zone
+    dp = plan.dp
+    dp_size = math.prod(mesh.shape[a] for a in dp)
+    local = batch // dp_size if batch is not None and batch % dp_size == 0 else None
+    split = kv_split(cfg.n_kv_heads, mesh.shape["model"], local)
+    attend = over_slots(mesh, plan) if split == "slots" else None
 
     def step(params, token, position, caches):
-        return decode_step(params, cfg, token, position, caches, constrain=c)
+        return decode_step(params, cfg, token, position, caches, constrain=c,
+                           attend=attend)
 
     p_specs = _sanitized_param_specs(cfg, plan, mesh)
-    k_specs = cache_pspecs(cfg, plan, mesh.shape["model"])
+    k_specs = cache_pspecs(cfg, plan, split)
     if batch is not None and max_len is not None:
         cache_shapes = jax.eval_shape(
             lambda: init_kv_cache(cfg, batch, max_len)
         )
         k_specs = sanitize_pspecs(k_specs, cache_shapes, mesh)
-    dp = plan.dp
+    entries = ", ".join(
+        f"{i} {kind}: " + (split or "none" if kind in ("attn", "local")
+                           else "channels")
+        for i, kind in enumerate(cfg.expanded_pattern))
+    print(f"decode cache split over model={mesh.shape['model']} "
+          f"({cfg.n_kv_heads} KV heads, {batch} slots): {entries}",
+          file=sys.stderr, flush=True)
     in_sh = (
         to_shardings(mesh, p_specs),
         NamedSharding(mesh, P(dp, None)),          # token [B,1]

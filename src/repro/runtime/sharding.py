@@ -24,6 +24,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
@@ -228,25 +229,51 @@ def make_constrain(mesh: Mesh, plan: ShardPlan, zone: int):
 
 # ------------------------------------------------------------------- caches
 
-def cache_pspecs(cfg: ModelConfig, plan: ShardPlan, model_size: int) -> tuple:
+# KV heads a device must hold for a head split: eight fill the sublane tile
+# of a bf16 ``[.., KV, hd]`` layer, T(8,128)(2,1), which attention reads at
+# full rate; with fewer the tile is padded, XLA copies each layer's K and V
+# out of the stack, and the dots read the padded tiles slowly.
+MIN_HEADS_A_DEVICE = 8
+
+
+def kv_split(n_kv_heads: int, model_size: int,
+             slots: int | None = None) -> str | None:
+    """The axis of the K/V caches ``[R, B, T, KV, hd]`` that the ``model``
+    axis (``model_size`` devices) splits, from shapes alone.
+
+    ``"heads"`` where each device then holds at least
+    ``MIN_HEADS_A_DEVICE`` KV heads; else ``"slots"`` where ``model_size``
+    divides the ``slots`` a data shard holds (each device then holds whole
+    slots, every head, all positions); else ``"heads"`` where they divide
+    the axis at all; else ``"positions"``.  None on one device."""
+    if model_size == 1:
+        return None
+    heads = n_kv_heads % model_size == 0
+    if heads and n_kv_heads // model_size >= MIN_HEADS_A_DEVICE:
+        return "heads"
+    if slots is not None and slots % model_size == 0:
+        return "slots"
+    return "heads" if heads else "positions"
+
+
+def cache_pspecs(cfg: ModelConfig, plan: ShardPlan, split: str | None) -> tuple:
     """Specs of the stacked decode caches ``[R, B, T, ...]``.
 
-    K/V ``[R, B, T, KV, hd]`` split over the KV heads where they divide the
-    ``model`` axis (``model_size`` devices): each device then holds the heads
-    its shard of ``wk``/``wv`` computes, so a prompt's K/V or a step's new
-    row is written where it is made, and attention reads only local K/V.
-    Where the heads do not divide the axis, K/V split over positions."""
+    K/V ``[R, B, T, KV, hd]`` split over the ``model`` axis on the axis
+    ``split`` (``kv_split``) names.  Over heads each device holds the heads
+    its shard of ``wk``/``wv`` computes, so a row is written where it is
+    made; over slots attention runs where its slots are (``over_slots``);
+    over positions GSPMD combines the shards' partial softmax.  Mamba and
+    rwkv states split over their channels."""
     dp = plan.dp
-    heads = cfg.n_kv_heads % model_size == 0
-    t_ax = None if heads else "model"
-    h_ax = "model" if heads else None
+    kv = {None: P(None, dp, None, "model", None),
+          "heads": P(None, dp, None, "model", None),
+          "slots": P(None, (*dp, "model"), None, None, None),
+          "positions": P(None, dp, "model", None, None)}[split]
     specs = []
     for kind in cfg.expanded_pattern:
         if kind in ("attn", "local"):
-            specs.append({
-                "k": P(None, dp, t_ax, h_ax, None),
-                "v": P(None, dp, t_ax, h_ax, None),
-            })
+            specs.append({"k": kv, "v": kv})
         elif kind == "mamba":
             specs.append({
                 "h": P(None, dp, "model", None),
@@ -259,6 +286,43 @@ def cache_pspecs(cfg: ModelConfig, plan: ShardPlan, model_size: int) -> tuple:
                 "shift_ffn": P(None, dp, None, None),
             })
     return tuple(specs)
+
+
+def over_slots(mesh: Mesh, plan: ShardPlan):
+    """``attend`` hook of ``models.decode_step`` where K/V split over slots.
+
+    The q/k/v projections arrive column-split over ``model`` (each device
+    holds its heads for every slot).  One all-to-all moves them, as one
+    array, from heads to slots; the device then writes its slots' new rows,
+    and runs scores, softmax and P·V on its own slots with every head and
+    its local cache; one all-to-all takes the output back to heads, where
+    the row-split ``wo`` and its all-reduce expect it."""
+    m = mesh.shape["model"]
+    slots = (*plan.dp, "model")
+    cols = P(plan.dp, None, "model")
+
+    def attend(core, q, k, v, position, cache_k, cache_v, layer):
+        widths = [a.shape[-1] // m for a in (q, k, v)]
+        stack = P(*[None] * (cache_k.ndim - 4), slots, None, None, None)
+
+        def local(q, k, v, position, cache_k, cache_v, layer):
+            qkv = jnp.concatenate([q, k, v], -1)            # [b, 1, c / m]
+            qkv = jax.lax.all_to_all(qkv, "model", 0, 1, tiled=True)
+            q, k, v = (a.reshape(a.shape[0], 1, -1) for a in jnp.split(
+                qkv, [widths[0], widths[0] + widths[1]], -1))
+            out, cache_k, cache_v = core(q, k, v, position, cache_k, cache_v,
+                                         layer)
+            out = out.reshape(out.shape[0], m, -1)          # [b / m, m, .]
+            out = jax.lax.all_to_all(out, "model", 1, 0, tiled=True)
+            return out, cache_k, cache_v
+
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(cols, cols, cols, P(slots), stack, stack, P()),
+            out_specs=(cols, stack, stack),
+        )(q, k, v, position, cache_k, cache_v, layer)
+
+    return attend
 
 
 def batch_pspecs(cfg: ModelConfig, plan: ShardPlan, with_labels: bool = True):

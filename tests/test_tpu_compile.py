@@ -176,16 +176,24 @@ def test_decode_writes_the_stacked_cache_in_place(cell_decode):
 def test_sharded_decode_gathers_no_cache(topo):
     """granite-3-8b (40 layers) decoding on the described 2x2 package under
     the plan ``scope.solve`` gives it: the carried caches keep their
-    shards, so no all-gather or all-to-all moves a layer of cache."""
+    shards, so no all-gather or all-to-all moves a layer of cache, and
+    nothing gathers a weight.  With 2 of the 8 KV heads a chip the cache
+    splits over slots, and each chip reads its layer of K and V as one
+    chip does: whole slots with all 8 heads, tiled (8, 128), not (2, 128)."""
     stack, compiled = _decode_compiled(topo.devices, tpu_v5e(4, (2, 2)), 16,
                                        2048, n_layers=40)
-    layer_shard = np.prod(stack[1:]) * 2 // len(topo.devices)   # bf16
+    n = len(topo.devices)
+    layer_shard = np.prod(stack[1:]) * 2 // n   # bf16
     ops = unfused_instructions(compiled.as_text())
     moves = [i for i in ops
              if i.opcode.removesuffix("-start") in ("all-gather", "all-to-all")]
     assert any(i.opcode.startswith("all-") for i in ops)
+    assert not [i for i in moves if i.opcode.startswith("all-gather")]
     for i in moves:
         assert shape_bytes(i.type) < layer_shard, i
+    local = (stack[1] // n, *stack[2:])
+    reads = [i for i in ops if i.dims in (local, (1, *local))]
+    assert reads and all("T(8,128)(2,1)" in i.type for i in reads), reads
 
 
 def test_prefill_step_fits_one_chip(one_chip):
